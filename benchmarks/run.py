@@ -664,6 +664,8 @@ def main(argv=None) -> None:
     p.add_argument("--out-dir", default=".",
                    help="where BENCH_<suite>.json artifacts are written")
     args = p.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     global SMALL
     SMALL = args.small
     os.makedirs(args.out_dir, exist_ok=True)

@@ -15,7 +15,9 @@ them statically:
     index * block dim), checking 0 <= offset and offset + block <= shape;
   * block shapes must divide the operand shape evenly — the invariant the
     kernels' ``assert``s and ``models/attention._divisor_block`` callers
-    guarantee at runtime, re-proven here for the representative shapes;
+    guarantee at runtime, re-proven here for the representative shapes —
+    and the last two dims of a VMEM block must be multiples of (8, 128) or
+    equal the operand's, as Mosaic requires;
   * the VMEM footprint is summed statically: input/output blocks counted
     TWICE (Pallas double-buffers the grid pipeline) plus scratch once,
     gated against a configurable budget (default 16 MB/v5e, per the note
@@ -168,6 +170,21 @@ def _audit_spec(launch: KernelLaunch, spec, operand, name: str,
                     f"{launch.kernel}/{name}: block dim {d} is {b}, which "
                     f"does not divide operand dim {s} "
                     f"(shape {tuple(operand.shape)})"), **where))
+    if space == "vmem":
+        # Mosaic tiling: the last two block dims must be multiples of
+        # (8, 128) or equal the operand's dims.
+        rank = len(block)
+        for d, tile in ((rank - 1, 128), (rank - 2, 8)):
+            if d < 0:
+                continue
+            b, s = block[d], operand.shape[d]
+            if b % tile and b != s:
+                findings.append(Finding(
+                    rule="kernel-block-divisibility", message=(
+                        f"{launch.kernel}/{name}: block dim {d} is {b}, "
+                        f"neither a multiple of {tile} nor the operand's "
+                        f"{s} (Mosaic tiling, shape "
+                        f"{tuple(operand.shape)})"), **where))
 
     grid_points = 1
     for g in launch.grid:

@@ -302,7 +302,8 @@ def registered_entries(mesh=None) -> List[TraceEntry]:
     from repro.optim import make_optimizer
 
     if mesh is None:
-        mesh = jax.sharding.AbstractMesh((("data", 2), ("model", 2)))
+        from repro.launch.mesh import make_abstract_mesh
+        mesh = make_abstract_mesh((2, 2), ("data", "model"))
     rules = shd.MEGATRON_RULES
     entries: List[TraceEntry] = []
     step_sds = _SDS((), jnp.int32)

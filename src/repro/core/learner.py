@@ -98,7 +98,7 @@ def make_train_step(agent_apply: Callable, opt, train_cfg, *,
             behavior_values=batch.get("behavior_value"),
             clear_policy_cost=train_cfg.clear_policy_cost,
             clear_value_cost=train_cfg.clear_value_cost,
-            vtrace_impl=vtrace_impl)
+            vtrace_impl=vtrace_impl, mesh=mesh)
         return loss_out.total, loss_out
 
     def train_step(params, opt_state, step, batch):
@@ -162,7 +162,7 @@ def make_recurrent_train_step(agent_apply, opt, train_cfg, *,
             entropy_cost=train_cfg.entropy_cost,
             clip_rho=train_cfg.vtrace_rho_clip,
             clip_c=train_cfg.vtrace_c_clip,
-            vtrace_impl=vtrace_impl)
+            vtrace_impl=vtrace_impl, mesh=mesh)
         return loss_out.total, loss_out
 
     def train_step(params, opt_state, step, batch):
@@ -234,7 +234,7 @@ def make_lm_train_step(cfg, opt, train_cfg, loss_chunk=512,
             entropy_cost=train_cfg.entropy_cost,
             clip_rho=train_cfg.vtrace_rho_clip,
             clip_c=train_cfg.vtrace_c_clip,
-            vtrace_impl=vtrace_impl)
+            vtrace_impl=vtrace_impl, mesh=mesh)
         lb, zl, _ = aux
         total = loss_out.total + cfg.router_aux_weight * lb \
             + cfg.router_z_weight * zl

@@ -14,6 +14,7 @@ paper's, recorded in EXPERIMENTS.md §Validation.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -39,16 +40,18 @@ def _reduce(x, reduce):
     return jnp.sum(x) if reduce == "sum" else jnp.sum(jnp.mean(x, axis=1))
 
 
-def _vtrace_fn(vtrace_impl):
+def _vtrace_fn(vtrace_impl, mesh=None):
     """Resolve the V-trace recursion implementation: the reverse-scan
     reference ('scan') or the Pallas TPU kernel ('kernel',
     kernels/vtrace.py — interpret-mode on CPU, same recursion blocked over
-    128-wide batch lanes held in VMEM)."""
+    128-wide batch lanes held in VMEM). ``mesh``: the learner's mesh, on
+    which the kernel runs per device over its batch columns."""
     if vtrace_impl == "scan":
         return vtrace_lib.vtrace_from_importance_weights
     if vtrace_impl == "kernel":
         from repro.kernels import ops
-        return ops.vtrace_from_importance_weights_kernel
+        return functools.partial(ops.vtrace_from_importance_weights_kernel,
+                                 mesh=mesh)
     raise ValueError(f"vtrace_impl must be 'scan' or 'kernel': "
                      f"{vtrace_impl!r}")
 
@@ -91,7 +94,7 @@ def impala_loss_from_logits(target_logits, behavior_logits, actions,
                             clip_rho=1.0, clip_c=1.0, reduce="mean",
                             is_replay=None, behavior_values=None,
                             clear_policy_cost=0.0, clear_value_cost=0.0,
-                            vtrace_impl="scan"):
+                            vtrace_impl="scan", mesh=None):
     """Paper-faithful path (full logits, small action spaces). All (T,B,...).
 
     target_logits/values carry gradients; behavior_* are data.
@@ -101,14 +104,15 @@ def impala_loss_from_logits(target_logits, behavior_logits, actions,
     network's value estimates recorded at generation time — the
     value-cloning anchor (without it only policy cloning is applied).
     vtrace_impl: 'scan' (reverse-scan reference) or 'kernel' (the Pallas
-    V-trace recursion, interpret-mode on CPU).
+    V-trace recursion, interpret-mode on CPU). mesh: the learner's mesh,
+    if any (the kernel is not partitioned by XLA; see ``_vtrace_fn``).
     """
     target_lp_all = jax.nn.log_softmax(target_logits.astype(jnp.float32), -1)
     target_lp = jnp.take_along_axis(target_lp_all, actions[..., None],
                                     axis=-1)[..., 0]
     behavior_lp = vtrace_lib._action_log_probs(behavior_logits, actions)
 
-    vt = _vtrace_fn(vtrace_impl)(
+    vt = _vtrace_fn(vtrace_impl, mesh)(
         jax.lax.stop_gradient(target_lp) - behavior_lp, discounts, rewards,
         jax.lax.stop_gradient(values), bootstrap_value,
         clip_rho_threshold=clip_rho, clip_c_threshold=clip_c)
@@ -140,11 +144,11 @@ def impala_loss_from_logprobs(target_logprobs, target_entropy,
                               behavior_logprobs, rewards, discounts, values,
                               bootstrap_value, *, baseline_cost=0.5,
                               entropy_cost=0.01, clip_rho=1.0, clip_c=1.0,
-                              reduce="mean", vtrace_impl="scan"):
+                              reduce="mean", vtrace_impl="scan", mesh=None):
     """LLM-scale path: (T,B) chosen-action log-probs + per-step entropy
     (computed chunked by the caller). target_logprobs/values/target_entropy
-    carry gradients. vtrace_impl as in ``impala_loss_from_logits``."""
-    vt = _vtrace_fn(vtrace_impl)(
+    carry gradients. vtrace_impl, mesh as in ``impala_loss_from_logits``."""
+    vt = _vtrace_fn(vtrace_impl, mesh)(
         jax.lax.stop_gradient(target_logprobs) - behavior_logprobs,
         discounts, rewards, jax.lax.stop_gradient(values), bootstrap_value,
         clip_rho_threshold=clip_rho, clip_c_threshold=clip_c)

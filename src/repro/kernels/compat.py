@@ -1,13 +1,8 @@
-"""Pallas-TPU API compatibility across jax versions and backends."""
+"""Interpret-or-compile selection for the Pallas kernels by backend."""
 
 import warnings
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
-
-# Renamed TPUCompilerParams -> CompilerParams after jax 0.4.x.
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
 
 _stats = {"explicit": 0, "compiled": 0, "fallbacks": 0}
 

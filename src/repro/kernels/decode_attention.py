@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 from repro.kernels.ops import NEG_INF
 
 
@@ -43,7 +42,7 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, slot_ref, o_ref,
     k = k_ref[0].astype(jnp.float32)                   # (bk, hd)
     v = v_ref[0].astype(jnp.float32)
     pos = pos_ref[pl.program_id(0) // kheads]          # this row's position
-    slot_pos = slot_ref[...]                           # (1, bk) int32
+    slot_pos = slot_ref[0]                             # (1, bk) int32
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -90,8 +89,10 @@ def decode_attention(q, k, v, slot_pos, pos, *, scale=None, softcap=0.0,
     qf = q.reshape(b * kheads, group, hd)
     kf = k.reshape(b * kheads, s, hd)
     vf = v.reshape(b * kheads, s, hd)
-    slot2d = jnp.broadcast_to(jnp.asarray(slot_pos, jnp.int32).reshape(-1, s),
-                              (b, s))
+    # (B, 1, S): a (1, 1, bk) block keeps bk on the lane axis and the
+    # sublane dim equal to the array's, as the Mosaic tiling rule requires.
+    slot3d = jnp.broadcast_to(
+        jnp.asarray(slot_pos, jnp.int32).reshape(-1, 1, s), (b, 1, s))
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
 
     kernel = functools.partial(_kernel, scale=scale, softcap=softcap,
@@ -106,7 +107,7 @@ def decode_attention(q, k, v, slot_pos, pos, *, scale=None, softcap=0.0,
             pl.BlockSpec((1, group, hd), lambda bh, ki: (bh, 0, 0)),
             pl.BlockSpec((1, bk, hd), lambda bh, ki: (bh, ki, 0)),
             pl.BlockSpec((1, bk, hd), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, bk), lambda bh, ki: (bh // kheads, ki)),
+            pl.BlockSpec((1, 1, bk), lambda bh, ki: (bh // kheads, 0, ki)),
         ],
         out_specs=pl.BlockSpec((1, group, hd), lambda bh, ki: (bh, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b * kheads, group, hd), q.dtype),
@@ -115,8 +116,8 @@ def decode_attention(q, k, v, slot_pos, pos, *, scale=None, softcap=0.0,
             pltpu.VMEM((group,), jnp.float32),
             pltpu.VMEM((group, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(pos_arr, qf, kf, vf, slot2d)
+    )(pos_arr, qf, kf, vf, slot3d)
     return out.reshape(b, h, hd)

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 from repro.kernels.ops import NEG_INF
 
 
@@ -134,7 +133,7 @@ def flash_attention(q, k, v, *, scale=None, causal=True, window=0,
             pltpu.VMEM((bq,), jnp.float32),       # l
             pltpu.VMEM((bq, hd), jnp.float32),    # acc
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)

@@ -8,6 +8,8 @@ kernel body for correctness checking in this container (DESIGN.md §8.5).
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -47,12 +49,28 @@ def vtrace_acc(deltas, dcs, *, block_b=128, interpret=None):
                            interpret=resolve_interpret(interpret))
 
 
+def _per_device_columns(fn, mesh, b):
+    """``fn`` over (T, B) arrays, run by each device of ``mesh`` on its
+    own batch columns: XLA does not partition a Mosaic kernel, so a
+    sharded caller must ``shard_map`` it. Replicated when B does not
+    divide over the data axes."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.distributed.sharding import data_axes
+    axes = data_axes(mesh)
+    size = math.prod(mesh.shape[a] for a in axes)
+    spec = P(None, axes) if axes and b % size == 0 else P()
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec),
+                         out_specs=spec, check_vma=False)
+
+
 def vtrace_from_importance_weights_kernel(
         log_rhos, discounts, rewards, values, bootstrap_value, *,
         clip_rho_threshold=1.0, clip_c_threshold=1.0,
-        clip_pg_rho_threshold=1.0, interpret=None):
+        clip_pg_rho_threshold=1.0, mesh=None, interpret=None):
     """Full V-trace with the recursion on the Pallas kernel (drop-in for
-    core.vtrace.vtrace_from_importance_weights)."""
+    core.vtrace.vtrace_from_importance_weights). ``mesh``: the caller's
+    device mesh, over whose data axes the recursion is split by column."""
     from repro.core.vtrace import VTraceReturns
 
     log_rhos = log_rhos.astype(jnp.float32)
@@ -67,7 +85,10 @@ def vtrace_from_importance_weights_kernel(
     values_tp1 = jnp.concatenate([values[1:], bootstrap_value[None]], 0)
     deltas = clipped_rhos * (rewards + discounts * values_tp1 - values)
 
-    acc = vtrace_acc(deltas, discounts * cs, interpret=interpret)
+    acc_fn = lambda d, dc: vtrace_acc(d, dc, interpret=interpret)  # noqa: E731
+    if mesh is not None:
+        acc_fn = _per_device_columns(acc_fn, mesh, deltas.shape[1])
+    acc = acc_fn(deltas, discounts * cs)
     vs = values + acc
     vs_tp1 = jnp.concatenate([vs[1:], bootstrap_value[None]], 0)
     pg_rhos = jnp.minimum(clip_pg_rho_threshold, rhos)

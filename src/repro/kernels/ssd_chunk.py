@@ -25,8 +25,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(c_ref, b_ref, x_ref, da_ref, h_ref, y_ref, hnew_ref, *, l, n, p):
@@ -36,12 +35,18 @@ def _kernel(c_ref, b_ref, x_ref, da_ref, h_ref, y_ref, hnew_ref, *, l, n, p):
     da = da_ref[0].astype(jnp.float32)        # (L, 1)
     h_prev = h_ref[0].astype(jnp.float32)     # (P, N)
 
-    acs = jnp.cumsum(da[:, 0])                # (L,)
-    # segsum: seg[i, j] = acs[i] - acs[j], masked lower-tri (incl diag)
-    seg = acs[:, None] - acs[None, :]
     ii = jax.lax.broadcasted_iota(jnp.int32, (l, l), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (l, l), 1)
-    lmat = jnp.where(jj <= ii, jnp.exp(seg), 0.0)   # (L, L)
+    causal = jj <= ii
+    # cumsum as a lower-triangular matmul (Mosaic has no cumsum); HIGHEST
+    # keeps the decay exponents at full fp32 on the MXU.
+    acs = jax.lax.dot_general(
+        causal.astype(jnp.float32), da, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)[:, 0]   # (L,)
+    # segsum: seg[i, j] = acs[i] - acs[j], masked lower-tri (incl diag)
+    seg = acs[:, None] - acs[None, :]
+    lmat = jnp.where(causal, jnp.exp(seg), 0.0)     # (L, L)
 
     scores = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -90,7 +95,7 @@ def ssd_chunk(c, b, xdt, da, h_prev, *, interpret=False):
             jax.ShapeDtypeStruct((bh, l, p), xdt.dtype),
             jax.ShapeDtypeStruct((bh, p, n), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(c, b, xdt, da, h_prev)

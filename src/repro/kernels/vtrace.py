@@ -19,8 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(deltas_ref, dcs_ref, acc_ref, *, t_len):
@@ -51,7 +50,7 @@ def vtrace_scan(deltas, dcs, *, block_b=128, interpret=False):
         ],
         out_specs=pl.BlockSpec((t, bb), lambda bi: (0, bi)),
         out_shape=jax.ShapeDtypeStruct((t, b), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(deltas.astype(jnp.float32), dcs.astype(jnp.float32))

@@ -15,22 +15,23 @@ import jax
 
 
 def make_mesh(shape, axes, devices=None):
-    """``jax.make_mesh`` with Auto axis types where this jax supports them
-    (``jax.sharding.AxisType`` does not exist on older 0.4.x releases).
-    ``devices``: optional explicit device array (defaults to all local
-    devices, as ``jax.make_mesh`` does)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
+    """``jax.make_mesh`` with Auto axis types. ``devices``: optional
+    explicit device array (defaults to all local devices, as
+    ``jax.make_mesh`` does)."""
+    axis_types = (jax.sharding.AxisType.Auto,) * len(axes)
     if devices is not None:
         import numpy as np
         devices = np.asarray(devices).reshape(shape)
-        if axis_type is None:
-            return jax.sharding.Mesh(devices, axes)
-        return jax.sharding.Mesh(devices, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+        return jax.sharding.Mesh(devices, axes, axis_types=axis_types)
+    return jax.make_mesh(shape, axes, axis_types=axis_types)
+
+
+def make_abstract_mesh(shape, axes):
+    """A device-free mesh of the same axis types as ``make_mesh`` — what
+    the static auditors and spec tests trace against."""
+    return jax.sharding.AbstractMesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -36,6 +36,7 @@ import numpy as np
 from repro.configs import get_config, get_reduced_config
 from repro.configs.base import ImplContext
 from repro.core.generate import DecodeSession
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model as model_lib
 
 
@@ -222,6 +223,7 @@ class Server:
 
 
 def main(argv=None):
+    use_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="qwen3-4b")
     p.add_argument("--reduced", action="store_true")

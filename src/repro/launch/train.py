@@ -50,6 +50,7 @@ from repro.configs.base import ImplContext, TrainConfig
 from repro.core import learner as learner_lib
 from repro.core import sources as sources_lib
 from repro.core.runtime import Runtime
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model as model_lib
 from repro.models.convnet import impala_deep, init_agent, minatar_net
 from repro.optim import make_optimizer
@@ -251,6 +252,10 @@ def _checkpoint_meta(args):
 
 
 def main(argv=None):
+    """Parse ``argv``, build the mode's source and learner step, and run
+    them; returns the finished ``Runtime`` (its ``params``, ``metrics``
+    and ``source``)."""
+    use_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--mode", choices=sorted(_BUILDERS), default="rl-agent")
     p.add_argument("--env", choices=["catch", "gridworld"], default="catch")
@@ -400,7 +405,7 @@ def main(argv=None):
                       checkpoint_every=args.checkpoint_every,
                       checkpoint_meta=_checkpoint_meta(args), **extras)
     runtime.run()
-    return runtime.params
+    return runtime
 
 
 if __name__ == "__main__":

@@ -197,14 +197,18 @@ def _attend_chunked(q, k, v, q_pos, k_pos, scale, window, cap, causal,
     return outs.transpose(1, 0, 2, 3, 4).reshape(b, sq, heads, hd)
 
 
-def _divisor_block(s, want):
-    """Largest divisor of ``s`` that is <= ``want`` — the kernel grids
-    require the sequence to tile exactly, and CI shapes are not always
-    multiples of 128."""
-    b = max(min(want, s), 1)
-    while s % b:
-        b -= 1
-    return b
+def _divisor_block(s, want, align):
+    """Block size for a kernel axis of length ``s``: the largest divisor of
+    ``s`` that is <= ``want`` and a multiple of ``align``, else ``s``
+    itself. The grids need the axis to tile exactly, and Mosaic needs a
+    block dim to be a multiple of its tile (8 sublanes, 128 lanes) or the
+    whole axis; CI shapes are not always multiples of 128."""
+    if s <= want:
+        return s
+    for b in range(want - want % align, 0, -align):
+        if s % b == 0:
+            return b
+    return s
 
 
 def _attend_flash_kernel(q, k, v, q_pos, k_pos, *, scale, window, cap,
@@ -221,8 +225,8 @@ def _attend_flash_kernel(q, k, v, q_pos, k_pos, *, scale, window, cap,
     heads. Positions are integer primals and get float0 cotangents.
     """
     from repro.kernels import ops as kops
-    bq = _divisor_block(q.shape[1], min(chunk, 128))
-    bk = _divisor_block(k.shape[1], min(chunk, 128))
+    bq = _divisor_block(q.shape[1], min(chunk, 128), 8)
+    bk = _divisor_block(k.shape[1], min(chunk, 128), 8)
 
     @jax.custom_vjp
     def attend(q, k, v, q_pos, k_pos):
@@ -387,7 +391,7 @@ def attn_decode(params, x, cache, *, cfg, kind, pos, impl=None):
             q[:, 0], k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
             slot_pos.astype(jnp.int32), pos.astype(jnp.int32),
             scale=_scale(cfg), softcap=cfg.attn_logit_softcap or 0.0,
-            window=window, block_k=_divisor_block(cap, 128))
+            window=window, block_k=_divisor_block(cap, 128, 128))
         return _out_proj(params, cfg, o[:, None]), {"k": k, "v": v}
 
     # grouped GQA einsum directly against the compact (B,S,K,hd) cache:

@@ -4,7 +4,6 @@ retrace hazard, dead donation, stale-mesh sharding axis, unlocked
 cross-thread write, leaked thread, hot-path host sync), waivers
 suppress findings, and the real codebase passes clean."""
 
-import functools
 import textwrap
 
 import jax
@@ -83,6 +82,22 @@ def test_kernel_audit_flags_non_dividing_block():
     assert "kernel-block-divisibility" in _rules(findings)
 
 
+def test_kernel_audit_flags_mosaic_untiled_block():
+    """A (1, 128) block over a (B, S) array: its sublane dim 1 is neither
+    a multiple of 8 nor B, which Mosaic refuses although it divides."""
+    kheads, bk = 4, 128
+    launch = KernelLaunch(
+        kernel="fixture", grid=(8 * kheads, 4),
+        in_specs=[pl.BlockSpec((1, bk), lambda bh, ki: (bh // kheads, ki))],
+        out_specs=[pl.BlockSpec((1, 1, bk),
+                                lambda bh, ki: (bh // kheads, 0, ki))],
+        operands=[_SDS((8, 512), jnp.int32)],
+        out_shapes=[_SDS((8, 1, 512), jnp.int32)], scratch_shapes=())
+    findings, _ = audit_launch(launch)
+    assert [f.rule for f in findings] == ["kernel-block-divisibility"]
+    assert "in0: block dim 0 is 1" in findings[0].message
+
+
 def test_kernel_audit_real_kernels_clean_and_complete():
     """The shipped kernels pass, and the footprint table covers all four
     kernels for every audited arch."""
@@ -102,8 +117,8 @@ def test_kernel_audit_real_kernels_clean_and_complete():
 # ---------------------------------------------------------------------------
 
 class _IdHashCfg:
-    """__eq__ by value but __hash__ by identity: the classic retrace
-    storm — every freshly built (but equal) config recompiles."""
+    """__eq__ by value but __hash__ by identity: equal configs hash
+    apart, which breaks every dict or cache keyed on them."""
 
     def __init__(self, d):
         self.d = d
@@ -112,6 +127,15 @@ class _IdHashCfg:
         return isinstance(other, _IdHashCfg) and self.d == other.d
 
     __hash__ = object.__hash__
+
+
+class _IdentityCfg:
+    """Equal contents, but no value ``__eq__``: every fresh instance is a
+    new jit cache key. JAX compares statics with ``__eq__`` when it looks
+    up a trace, so this (not ``_IdHashCfg``) is what retraces."""
+
+    def __init__(self, d):
+        self.d = d
 
 
 class _UnhashableCfg:
@@ -139,7 +163,7 @@ def test_audit_entry_flags_retrace_from_id_hash_static():
     entry = TraceEntry(
         name="fixture-retrace", fn=fn,
         make_args=lambda: ((_SDS((4,), jnp.float32),),
-                           {"cfg": _IdHashCfg(3)}),
+                           {"cfg": _IdentityCfg(3)}),
         jit_kwargs={"static_argnames": ("cfg",)})
     findings, summary = audit_entry(entry)
     assert "retrace-hazard" in _rules(findings)
@@ -164,8 +188,9 @@ def test_audit_entry_flags_dead_donation():
 def test_audit_entry_flags_stale_mesh_axis():
     """A sharding constraint built on a mesh whose axes are not live on
     the entry's declared mesh."""
-    live = jax.sharding.AbstractMesh((("data", 2),))
-    stale = jax.sharding.AbstractMesh((("model", 2),))
+    from repro.launch.mesh import make_abstract_mesh
+    live = make_abstract_mesh((2,), ("data",))
+    stale = make_abstract_mesh((2,), ("model",))
     P = jax.sharding.PartitionSpec
 
     def fn(x):

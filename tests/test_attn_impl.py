@@ -103,6 +103,20 @@ def test_attn_decode_kernel_ring_buffer():
             np.testing.assert_allclose(outs["xla"], outs["kernel"], **TOL)
 
 
+@pytest.mark.parametrize("s,want,align,block", [
+    (37, 128, 8, 37),        # short axis: one whole block
+    (256, 128, 8, 128),
+    (200, 128, 8, 40),       # 100 divides 200 but is no multiple of 8
+    (130, 128, 8, 130),      # no aligned divisor: the whole axis
+    (512, 128, 128, 128),
+    (200, 128, 128, 200),
+])
+def test_divisor_block_keeps_mosaic_tiling(s, want, align, block):
+    """Kernel blocks tile the axis exactly and are a multiple of the tile
+    or the whole axis (what Mosaic accepts on the chip)."""
+    assert A._divisor_block(s, want, align) == block
+
+
 # ---------------------------------------------------------------------------
 # whole-model fwd/bwd parity for every arch with a kernel-served mixer
 # ---------------------------------------------------------------------------

@@ -23,6 +23,7 @@ from repro.configs import get_reduced_config
 from repro.distributed.sharding import (RL_AGENT_RULES, RULE_SETS,
                                         batch_axes_spec, data_axes,
                                         logical_to_mesh)
+from repro.launch.mesh import make_abstract_mesh
 from repro.models import model as model_lib
 from repro.models.common import split_params
 
@@ -35,10 +36,9 @@ _RULES_NAMES = sorted(RULE_SETS)
 
 
 def _mesh(data=1, model=1, pod=None):
-    shape = (("data", data), ("model", model))
     if pod:
-        shape = (("pod", pod),) + shape
-    return jax.sharding.AbstractMesh(shape)
+        return make_abstract_mesh((pod, data, model), ("pod", "data", "model"))
+    return make_abstract_mesh((data, model), ("data", "model"))
 
 
 def _assert_valid(spec, mesh, shape=None):
