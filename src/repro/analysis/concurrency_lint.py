@@ -53,6 +53,8 @@ HOT_ALLOWLIST: Dict[str, str] = {
     "DecodeSession.step": "host API: returns numpy arrays",
     "Server.submit": "host API: validates/copies the incoming prompt",
     "Server._finish": "host API: materializes the finished request",
+    "Runtime._log": "log line: the windowed fps waits for the logged "
+                    "step, only every log_every steps",
 }
 
 HOST_SYNC_ATTRS = {"item", "block_until_ready", "device_get"}

@@ -18,6 +18,9 @@ them statically:
     guarantee at runtime, re-proven here for the representative shapes —
     and the last two dims of a VMEM block must be multiples of (8, 128) or
     equal the operand's, as Mosaic requires;
+  * every launch carries a ``name=``, so a device trace names the kernel
+    (``kernel-unnamed`` otherwise: the trace names it after whichever
+    jitted function encloses it);
   * the VMEM footprint is summed statically: input/output blocks counted
     TWICE (Pallas double-buffers the grid pipeline) plus scratch once,
     gated against a configurable budget (default 16 MB/v5e, per the note
@@ -65,6 +68,7 @@ class KernelLaunch:
     scratch_shapes: Tuple[Any, ...]       # pltpu MemoryRefs
     file: str = ""
     line: int = 0
+    name: Optional[str] = None            # pallas_call's name=, if given
     operand_names: Optional[Sequence[str]] = None
     out_names: Optional[Sequence[str]] = None
 
@@ -78,7 +82,7 @@ def capture_launches(records: List[KernelLaunch], kernel_name: str,
     real = pl.pallas_call
 
     def fake(kernel, *, grid=None, in_specs=None, out_specs=None,
-             out_shape=None, scratch_shapes=(), **_kw):
+             out_shape=None, scratch_shapes=(), name=None, **_kw):
         def runner(*operands):
             outs_multi = isinstance(out_shape, (list, tuple))
             out_list = list(out_shape) if outs_multi else [out_shape]
@@ -95,7 +99,7 @@ def capture_launches(records: List[KernelLaunch], kernel_name: str,
                 out_shapes=[jax.ShapeDtypeStruct(s.shape, s.dtype)
                             for s in out_list],
                 scratch_shapes=tuple(scratch_shapes or ()),
-                file=file, line=line))
+                file=file, line=line, name=name))
             outs = [jnp.zeros(s.shape, s.dtype) for s in out_list]
             return outs if outs_multi else outs[0]
         return runner
@@ -226,6 +230,16 @@ def _audit_spec(launch: KernelLaunch, spec, operand, name: str,
             "bytes": _bytes(block, operand.dtype)}
 
 
+def audit_name(launch: KernelLaunch) -> List[Finding]:
+    """``kernel-unnamed`` for a launch without ``name=``: a device trace
+    then names it after whichever jitted function encloses it."""
+    if launch.name:
+        return []
+    return [Finding(rule="kernel-unnamed", file=launch.file,
+                    line=launch.line,
+                    message=f"{launch.kernel}: pallas_call without name=")]
+
+
 def audit_launch(launch: KernelLaunch, *,
                  vmem_budget: int = VMEM_BUDGET_BYTES,
                  smem_budget: int = SMEM_BUDGET_BYTES,
@@ -275,6 +289,7 @@ def audit_launch(launch: KernelLaunch, *,
 
     table = {
         "kernel": launch.kernel,
+        "name": launch.name,
         "grid": list(launch.grid),
         "operands": rows,
         "vmem_block_bytes": block_vmem,
@@ -445,7 +460,7 @@ def audit_kernels(archs: Optional[Sequence[str]] = None, *,
                     fnd, table = audit_launch(
                         launch, vmem_budget=vmem_budget,
                         smem_budget=smem_budget)
-                    findings.extend(fnd)
+                    findings.extend(fnd + audit_name(launch))
                     table.update(
                         arch=arch, shape=case["shape"],
                         hot_path=case.get(

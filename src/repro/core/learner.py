@@ -59,6 +59,14 @@ def _make_lm_mesh_fns(mesh, rules):
             lambda batch: sharding_lib.shard_lm_batch(batch, mesh, rules))
 
 
+def _optimizer_step(opt, grads, opt_state, params, step):
+    """The update of the RL learner steps, under the ``optimizer`` scope
+    that names its ops in a device trace."""
+    with jax.named_scope("optimizer"):
+        updates, opt_state = opt.update(grads, opt_state, params, step)
+        return apply_updates(params, updates), opt_state
+
+
 def make_train_step(agent_apply: Callable, opt, train_cfg, *,
                     mesh=None, rules=None, vtrace_impl="scan"):
     """Paper-faithful IMPALA learner step over a rollout batch.
@@ -82,31 +90,34 @@ def make_train_step(agent_apply: Callable, opt, train_cfg, *,
     shard_batch, shard_grads = _make_shard_fns(mesh, rules)
 
     def loss_fn(params, batch):
-        out = agent_apply(params, batch["obs"])       # (T+1, B, ...)
-        target_logits = out.policy_logits[:-1]
-        values = out.baseline[:-1]
-        bootstrap = jax.lax.stop_gradient(out.baseline[-1])
-        discounts = (~batch["done"]).astype(jnp.float32) * train_cfg.discount
-        loss_out = losses.impala_loss_from_logits(
-            target_logits, batch["behavior_logits"], batch["action"],
-            batch["reward"], discounts, values, bootstrap,
-            baseline_cost=train_cfg.baseline_cost,
-            entropy_cost=train_cfg.entropy_cost,
-            clip_rho=train_cfg.vtrace_rho_clip,
-            clip_c=train_cfg.vtrace_c_clip,
-            is_replay=batch.get("is_replay"),
-            behavior_values=batch.get("behavior_value"),
-            clear_policy_cost=train_cfg.clear_policy_cost,
-            clear_value_cost=train_cfg.clear_value_cost,
-            vtrace_impl=vtrace_impl, mesh=mesh)
+        with jax.named_scope("learner_forward"):
+            out = agent_apply(params, batch["obs"])   # (T+1, B, ...)
+        with jax.named_scope("loss"):
+            target_logits = out.policy_logits[:-1]
+            values = out.baseline[:-1]
+            bootstrap = jax.lax.stop_gradient(out.baseline[-1])
+            discounts = (~batch["done"]).astype(jnp.float32) \
+                * train_cfg.discount
+            loss_out = losses.impala_loss_from_logits(
+                target_logits, batch["behavior_logits"], batch["action"],
+                batch["reward"], discounts, values, bootstrap,
+                baseline_cost=train_cfg.baseline_cost,
+                entropy_cost=train_cfg.entropy_cost,
+                clip_rho=train_cfg.vtrace_rho_clip,
+                clip_c=train_cfg.vtrace_c_clip,
+                is_replay=batch.get("is_replay"),
+                behavior_values=batch.get("behavior_value"),
+                clear_policy_cost=train_cfg.clear_policy_cost,
+                clear_value_cost=train_cfg.clear_value_cost,
+                vtrace_impl=vtrace_impl, mesh=mesh)
         return loss_out.total, loss_out
 
     def train_step(params, opt_state, step, batch):
         batch = shard_batch(batch)
         grads, loss_out = jax.grad(loss_fn, has_aux=True)(params, batch)
         grads = shard_grads(grads)
-        updates, opt_state = opt.update(grads, opt_state, params, step)
-        params = apply_updates(params, updates)
+        params, opt_state = _optimizer_step(opt, grads, opt_state, params,
+                                            step)
         if "is_replay" in batch:
             fresh = (~batch["is_replay"]).astype(jnp.float32)[None, :]
             reward_per_step = (batch["reward"] * fresh).sum() \
@@ -148,29 +159,32 @@ def make_recurrent_train_step(agent_apply, opt, train_cfg, *,
         # re-run the recurrence over the T+1 observations from the stored
         # initial core_state; pre_done[t] zeroes the state exactly where
         # the actor did (fresh-episode observations)
-        _, (logits, baselines) = jax.lax.scan(
-            step, batch["core_state"], (batch["obs"], batch["pre_done"]))
-        t = batch["action"].shape[0]
-        target_logits = logits[:t]
-        values = baselines[:t]
-        bootstrap = jax.lax.stop_gradient(baselines[t])
-        discounts = (~batch["done"]).astype(jnp.float32) * train_cfg.discount
-        loss_out = losses.impala_loss_from_logits(
-            target_logits, batch["behavior_logits"], batch["action"],
-            batch["reward"], discounts, values, bootstrap,
-            baseline_cost=train_cfg.baseline_cost,
-            entropy_cost=train_cfg.entropy_cost,
-            clip_rho=train_cfg.vtrace_rho_clip,
-            clip_c=train_cfg.vtrace_c_clip,
-            vtrace_impl=vtrace_impl, mesh=mesh)
+        with jax.named_scope("learner_forward"):
+            _, (logits, baselines) = jax.lax.scan(
+                step, batch["core_state"], (batch["obs"], batch["pre_done"]))
+        with jax.named_scope("loss"):
+            t = batch["action"].shape[0]
+            target_logits = logits[:t]
+            values = baselines[:t]
+            bootstrap = jax.lax.stop_gradient(baselines[t])
+            discounts = (~batch["done"]).astype(jnp.float32) \
+                * train_cfg.discount
+            loss_out = losses.impala_loss_from_logits(
+                target_logits, batch["behavior_logits"], batch["action"],
+                batch["reward"], discounts, values, bootstrap,
+                baseline_cost=train_cfg.baseline_cost,
+                entropy_cost=train_cfg.entropy_cost,
+                clip_rho=train_cfg.vtrace_rho_clip,
+                clip_c=train_cfg.vtrace_c_clip,
+                vtrace_impl=vtrace_impl, mesh=mesh)
         return loss_out.total, loss_out
 
     def train_step(params, opt_state, step, batch):
         batch = shard_batch(batch)
         grads, loss_out = jax.grad(loss_fn, has_aux=True)(params, batch)
         grads = shard_grads(grads)
-        updates, opt_state = opt.update(grads, opt_state, params, step)
-        params = apply_updates(params, updates)
+        params, opt_state = _optimizer_step(opt, grads, opt_state, params,
+                                            step)
         metrics = {"loss": loss_out.total, "pg_loss": loss_out.pg_loss,
                    "entropy_loss": loss_out.entropy_loss,
                    "reward_per_step": batch["reward"].mean()}

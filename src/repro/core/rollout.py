@@ -33,12 +33,14 @@ def make_unroll(env, agent_apply, unroll_length: int):
     def unroll(params, carry, key):
         def one_step(carry, key):
             env_state, obs = carry
-            out = agent_apply(params, obs)
-            b = obs.shape[0]
-            action = jax.random.categorical(key, out.policy_logits)
-            keys = jax.random.split(jax.random.fold_in(key, 1), b)
-            env_state, next_obs, reward, done = v_step(env_state, action,
-                                                       keys)
+            with jax.named_scope("actor_forward"):
+                out = agent_apply(params, obs)
+                action = jax.random.categorical(key, out.policy_logits)
+            with jax.named_scope("env_step"):
+                keys = jax.random.split(jax.random.fold_in(key, 1),
+                                        obs.shape[0])
+                env_state, next_obs, reward, done = v_step(env_state,
+                                                           action, keys)
             step_data = {
                 "obs": obs,
                 "action": action.astype(jnp.int32),
@@ -48,15 +50,19 @@ def make_unroll(env, agent_apply, unroll_length: int):
             }
             return (env_state, next_obs), step_data
 
-        keys = jax.random.split(key, unroll_length)
-        carry, traj = jax.lax.scan(one_step, carry, keys)
-        rollout = {
-            "obs": jnp.concatenate([traj["obs"], carry[1][None]], axis=0),
-            "action": traj["action"],
-            "behavior_logits": traj["behavior_logits"],
-            "reward": traj["reward"],
-            "done": traj["done"],
-        }
+        # "rollout" holds the scan itself: stacking each step's outputs
+        # and the T+1 observations (the body's ops keep their own scopes)
+        with jax.named_scope("rollout"):
+            keys = jax.random.split(key, unroll_length)
+            carry, traj = jax.lax.scan(one_step, carry, keys)
+            rollout = {
+                "obs": jnp.concatenate([traj["obs"], carry[1][None]],
+                                       axis=0),
+                "action": traj["action"],
+                "behavior_logits": traj["behavior_logits"],
+                "reward": traj["reward"],
+                "done": traj["done"],
+            }
         return carry, rollout
 
     return unroll
@@ -99,12 +105,14 @@ def make_recurrent_unroll(env, agent_apply, agent_initial_state,
 
         def one_step(c, key):
             env_state, obs, core_state, pre_done = c
-            out = agent_apply(params, obs, core_state, pre_done)
-            b = obs.shape[0]
-            action = jax.random.categorical(key, out.policy_logits)
-            keys = jax.random.split(jax.random.fold_in(key, 1), b)
-            env_state, next_obs, reward, next_done = v_step(
-                env_state, action, keys)
+            with jax.named_scope("actor_forward"):
+                out = agent_apply(params, obs, core_state, pre_done)
+                action = jax.random.categorical(key, out.policy_logits)
+            with jax.named_scope("env_step"):
+                keys = jax.random.split(jax.random.fold_in(key, 1),
+                                        obs.shape[0])
+                env_state, next_obs, reward, next_done = v_step(
+                    env_state, action, keys)
             step_data = {
                 "obs": obs,
                 "pre_done": pre_done,  # obs[t] starts a fresh episode
@@ -116,19 +124,20 @@ def make_recurrent_unroll(env, agent_apply, agent_initial_state,
             return (env_state, next_obs, out.core_state, next_done), \
                 step_data
 
-        keys = jax.random.split(key, unroll_length)
-        (env_state, obs, core_state, done), traj = jax.lax.scan(
-            one_step, (env_state, obs, core_state, done0), keys)
-        rollout = {
-            "obs": jnp.concatenate([traj["obs"], obs[None]], axis=0),
-            "pre_done": jnp.concatenate([traj["pre_done"], done[None]],
-                                        axis=0),
-            "action": traj["action"],
-            "behavior_logits": traj["behavior_logits"],
-            "reward": traj["reward"],
-            "done": traj["done"],
-            "core_state": initial_core,
-        }
+        with jax.named_scope("rollout"):
+            keys = jax.random.split(key, unroll_length)
+            (env_state, obs, core_state, done), traj = jax.lax.scan(
+                one_step, (env_state, obs, core_state, done0), keys)
+            rollout = {
+                "obs": jnp.concatenate([traj["obs"], obs[None]], axis=0),
+                "pre_done": jnp.concatenate([traj["pre_done"], done[None]],
+                                            axis=0),
+                "action": traj["action"],
+                "behavior_logits": traj["behavior_logits"],
+                "reward": traj["reward"],
+                "done": traj["done"],
+                "core_state": initial_core,
+            }
         return (env_state, obs, core_state, done), rollout
 
     unroll.initial_carry = initial_carry

@@ -28,7 +28,9 @@ from __future__ import annotations
 import time
 from typing import Any, Callable, Dict, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 # Pretty-names for the metric keys the learner steps emit.
 _METRIC_LABELS = {"reward_per_step": "reward/step"}
@@ -132,7 +134,10 @@ class Runtime:
                       "replay_hit_rate"):
                 if k in stats:
                     parts.append(f"{k.removeprefix('replay_')}={stats[k]:.3f}")
-        now = time.time()
+        # Time the window to the logged step's completion, not to its
+        # dispatch: the host runs ahead of the device.
+        jax.block_until_ready(self.metrics)
+        now = time.monotonic()
         if self.frames:
             # Windowed throughput (since the previous log line) is the
             # headline number — a lifetime average masks late slowdowns.
@@ -166,43 +171,52 @@ class Runtime:
         # happens here; disk I/O and the multi-host completion barrier run
         # on the writer thread ("saved {path}" is printed there once the
         # manifest lands).
-        snap = ckpt_lib.snapshot({"params": self.params,
-                                  "opt_state": self.opt_state},
-                                 structured=None if source_state is None
-                                 else {"source": source_state})
-        if self._writer is None:
-            self._writer = AsyncCheckpointWriter(print_fn=self.print_fn)
-        self._writer.submit(path, snap,
-                            {"step": step, **self.checkpoint_meta})
+        with TraceAnnotation("runtime.checkpoint"):
+            snap = ckpt_lib.snapshot({"params": self.params,
+                                      "opt_state": self.opt_state},
+                                     structured=None if source_state is None
+                                     else {"source": source_state})
+            if self._writer is None:
+                self._writer = AsyncCheckpointWriter(print_fn=self.print_fn)
+            self._writer.submit(path, snap,
+                                {"step": step, **self.checkpoint_meta})
 
     # -- the loop -----------------------------------------------------------
 
     def run(self):
         """Run the loop; returns (params, opt_state)."""
         self.source.start(self.params)
-        t0 = time.time()
+        t0 = time.monotonic()
         self._win_t, self._win_frames = t0, self.frames
         # The next step whose update is NOT yet in params/opt_state — the
         # step a crash checkpoint must tell --resume to run first.
         next_step = self.start_step
         try:
             for step in range(self.start_step, self.total_steps):
-                batch = self.source.next_batch(self.params)
-                self.params, self.opt_state, self.metrics = self.step_fn(
-                    self.params, self.opt_state, jnp.int32(step), batch)
-                next_step = step + 1
-                self.frames += self.source.frames_per_batch
-                if self._feedback is not None:
-                    self._feedback(step, self.metrics)
-                if self.on_metrics is not None:
-                    self.on_metrics(step, self.metrics)
-                if self.log_every and (step % self.log_every == 0
-                                       or step == self.total_steps - 1):
-                    self._log(step, t0)
-                if (self.checkpoint_dir and self.checkpoint_every
-                        and (step + 1) % self.checkpoint_every == 0
-                        and step + 1 < self.total_steps):
-                    self._checkpoint(step + 1)
+                # Host spans on the profiler's clock (README "Tracing"),
+                # a few microseconds a step while no trace is taken.
+                with StepTraceAnnotation("train", step_num=step):
+                    with TraceAnnotation("source.next_batch"):
+                        batch = self.source.next_batch(self.params)
+                    with TraceAnnotation("learner.dispatch"):
+                        self.params, self.opt_state, self.metrics = \
+                            self.step_fn(self.params, self.opt_state,
+                                         jnp.int32(step), batch)
+                    next_step = step + 1
+                    self.frames += self.source.frames_per_batch
+                    with TraceAnnotation("runtime.callbacks"):
+                        if self._feedback is not None:
+                            self._feedback(step, self.metrics)
+                        if self.on_metrics is not None:
+                            self.on_metrics(step, self.metrics)
+                    if self.log_every and (step % self.log_every == 0
+                                           or step == self.total_steps - 1):
+                        with TraceAnnotation("runtime.log"):
+                            self._log(step, t0)
+                    if (self.checkpoint_dir and self.checkpoint_every
+                            and (step + 1) % self.checkpoint_every == 0
+                            and step + 1 < self.total_steps):
+                        self._checkpoint(step + 1)
             # The final checkpoint is written BEFORE source.stop(): stop()
             # resets dispatch state and recycles replay slots, and the
             # checkpoint must capture the LIVE source state so a later

@@ -118,6 +118,7 @@ def decode_attention(q, k, v, slot_pos, pos, *, scale=None, softcap=0.0,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="decode_attention",
         interpret=interpret,
     )(pos_arr, qf, kf, vf, slot3d)
     return out.reshape(b, h, hd)

@@ -135,6 +135,7 @@ def flash_attention(q, k, v, *, scale=None, causal=True, window=0,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_attention",
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(b, h, s, hd)

@@ -97,5 +97,6 @@ def ssd_chunk(c, b, xdt, da, h_prev, *, interpret=False):
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
+        name="ssd_chunk",
         interpret=interpret,
     )(c, b, xdt, da, h_prev)

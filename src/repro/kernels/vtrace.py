@@ -52,5 +52,6 @@ def vtrace_scan(deltas, dcs, *, block_b=128, interpret=False):
         out_shape=jax.ShapeDtypeStruct((t, b), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
+        name="vtrace",
         interpret=interpret,
     )(deltas.astype(jnp.float32), dcs.astype(jnp.float32))

@@ -13,7 +13,8 @@ from jax.experimental import pallas as pl
 from repro.analysis.common import Finding, apply_waivers
 from repro.analysis.concurrency_lint import lint_file, lint_tree
 from repro.analysis.kernel_audit import (KernelLaunch, audit_kernels,
-                                         audit_launch, capture_launches)
+                                         audit_launch, audit_name,
+                                         capture_launches)
 from repro.analysis.trace_audit import (TraceEntry, audit_entry,
                                         audit_static_key, audit_traces)
 
@@ -96,6 +97,37 @@ def test_kernel_audit_flags_mosaic_untiled_block():
     findings, _ = audit_launch(launch)
     assert [f.rule for f in findings] == ["kernel-block-divisibility"]
     assert "in0: block dim 0 is 1" in findings[0].message
+
+
+def test_kernel_audit_flags_unnamed_launch():
+    """A pallas_call without name= shows in a device trace as _kernel:
+    the audit records each launch's name and flags the missing one."""
+
+    def launch(x, name=None):
+        return pl.pallas_call(
+            lambda x_ref, o_ref: None, grid=(1,),
+            in_specs=[pl.BlockSpec((8, 128), lambda i: (0, 0))],
+            out_specs=pl.BlockSpec((8, 128), lambda i: (0, 0)),
+            out_shape=_SDS((8, 128), jnp.float32), name=name)(x)
+
+    x = _SDS((8, 128), jnp.float32)
+    records = []
+    with capture_launches(records, "fixture"):
+        jax.eval_shape(launch, x)
+        jax.eval_shape(lambda x: launch(x, name="fixture"), x)
+    unnamed, named = records
+    assert unnamed.name is None and named.name == "fixture"
+    assert _rules(audit_name(unnamed)) == {"kernel-unnamed"}
+    assert audit_name(named) == []
+    assert audit_launch(named)[1]["name"] == "fixture"
+
+
+def test_kernel_audit_real_kernels_named():
+    """Each shipped kernel launches under its own name."""
+    _, tables = audit_kernels(["zamba2-2.7b"])
+    assert {t["kernel"]: t["name"] for t in tables} == {
+        k: k for k in ("flash_attention", "decode_attention", "ssd_chunk",
+                       "vtrace")}
 
 
 def test_kernel_audit_real_kernels_clean_and_complete():

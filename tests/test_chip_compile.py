@@ -10,6 +10,7 @@ workers import this file would fail all but one of them.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +61,7 @@ def _compile(fn, sharding, *shapes):
     with jax.default_matmul_precision("default"):
         text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 @pytest.mark.parametrize("t", [20, 80])
@@ -101,3 +103,32 @@ def test_ssd_chunk_compiles(one_chip, length):
     _compile(fn, one_chip, ((bh, length, n), F32), ((bh, length, n), F32),
              ((bh, length, p), F32), ((bh, length, 1), F32),
              ((bh, p, n), F32))
+
+
+def test_kernels_named_in_compiled_program(one_chip):
+    """The compiled program names each kernel's custom call after its
+    pallas_call name=, which is what a device trace shows."""
+    cases = {
+        "vtrace": (functools.partial(vt.vtrace_scan, interpret=False),
+                   ((8, 128), F32), ((8, 128), F32)),
+        "flash_attention": (
+            functools.partial(fa.flash_attention, block_q=128, block_k=128,
+                              interpret=False),
+            ((1, 2, 128, 128), BF16), ((1, 2, 128, 128), BF16),
+            ((1, 2, 128, 128), BF16)),
+        "decode_attention": (
+            functools.partial(dec.decode_attention, block_k=128,
+                              interpret=False),
+            ((8, 2, 128), BF16), ((8, 2, 128, 128), BF16),
+            ((8, 2, 128, 128), BF16), ((8, 128), I32), ((8,), I32)),
+        "ssd_chunk": (functools.partial(ssd.ssd_chunk, interpret=False),
+                      ((2, 64, 64), F32), ((2, 64, 64), F32),
+                      ((2, 64, 64), F32), ((2, 64, 1), F32),
+                      ((2, 64, 64), F32)),
+    }
+    for name, (fn, *shapes) in cases.items():
+        text = _compile(fn, one_chip, *shapes)
+        calls = re.findall(r"%([\w.]+) = .*? custom-call\(.*"
+                           r'custom_call_target="tpu_custom_call"', text)
+        assert calls and all(c.split(".")[0] == name for c in calls), \
+            (name, calls)

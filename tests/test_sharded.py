@@ -511,8 +511,8 @@ def test_windowed_fps_reflects_recent_rate(monkeypatch):
     rt.print_fn = lines.append
     rt.metrics = {}
     clock = iter([0.0, 1.0, 2.0])  # t0, first _log, second _log
-    monkeypatch.setattr(runtime_mod.time, "time", lambda: next(clock))
-    t0 = runtime_mod.time.time()
+    monkeypatch.setattr(runtime_mod.time, "monotonic", lambda: next(clock))
+    t0 = runtime_mod.time.monotonic()
     rt._win_t, rt._win_frames = t0, 0
     rt.frames = 1000
     rt._log(0, t0)                 # 1000 frames in 1s
