@@ -53,7 +53,7 @@ SMEM_BUDGET_BYTES = 256 * 1024            # scalar memory: small by design
 GRID_LIMIT = 2_000_000                    # defensive cap on exhaustion
 
 AUDIT_KERNELS = ("flash_attention", "decode_attention", "ssd_chunk",
-                 "vtrace")
+                 "vtrace", "maxpool_fwd", "maxpool_bwd")
 
 
 @dataclasses.dataclass
@@ -372,7 +372,7 @@ def _ssd_cases(cfg):
     raw = _unwrapped(mod.ssd_chunk)
     file, line = _src(mod.ssd_chunk)
     # archs without a mamba mixer are audited at canonical SSD dims so the
-    # footprint table covers all four kernels for every config
+    # footprint table covers every kernel for every config
     if cfg.ssm_state and cfg.ssm_head_dim:
         n, p = cfg.ssm_state, cfg.ssm_head_dim
         l = cfg.ssm_chunk
@@ -413,11 +413,40 @@ def _vtrace_cases(cfg):
     }
 
 
+def _maxpool_cases(cfg, kernel):
+    """The IMPALA deep ResNet's first and last pools at the learner batch
+    of T=80, B=32 (2,592 frames): 84->42 (column blocks) and 21->11 (odd,
+    channel blocks)."""
+    del cfg                                # shape is arch-independent
+    from repro.kernels import maxpool as mod
+    n = 81 * 32
+    for h, c in ((84, 16), (21, 32)):
+        ho = mod.pooled_size(h)
+        if kernel == "maxpool_fwd":
+            raw, hw = _unwrapped(mod.maxpool_fwd), {}
+            args = (jax.ShapeDtypeStruct((h, h, c, n), jnp.float32),)
+            names = (("x", "x_halo"), ("pooled", "index"))
+        else:
+            raw, hw = _unwrapped(mod.maxpool_bwd), {"hw": (h, h)}
+            args = (jax.ShapeDtypeStruct((ho, ho, c, n), jnp.int8),
+                    jax.ShapeDtypeStruct((ho, ho, c, n), jnp.float32))
+            names = (("dy", "index", "dy_halo", "index_halo"), ("dx",))
+        file, line = _src(raw)
+        yield {
+            "kernel": kernel, "shape": f"{h}x{h}x{c}_n{n}",
+            "call": (functools.partial(raw, interpret=False, **hw), args),
+            "file": file, "line": line, "names": names,
+            "roofline": dict(dtype_bytes=4, h=h, w=h, c=c, n=n),
+        }
+
+
 _CASE_BUILDERS = {
     "flash_attention": _flash_cases,
     "decode_attention": _decode_cases,
     "ssd_chunk": _ssd_cases,
     "vtrace": _vtrace_cases,
+    "maxpool_fwd": functools.partial(_maxpool_cases, kernel="maxpool_fwd"),
+    "maxpool_bwd": functools.partial(_maxpool_cases, kernel="maxpool_bwd"),
 }
 
 

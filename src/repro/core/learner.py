@@ -28,16 +28,20 @@ from repro.optim.optimizers import apply_updates
 
 
 def _make_shard_fns(mesh, rules):
-    """(batch constrainer, grad constrainer) for a (mesh, rules) context;
-    both identity when no mesh is given (the single-device path compiles
-    to the exact same program as before)."""
+    """(batch constrainer, grad constrainer, model-call context factory)
+    for a (mesh, rules) context; identities when no mesh is given (the
+    single-device path compiles to the exact same program as before). The
+    context lets the model's Pallas kernels run per device
+    (``models/convnet._maxpool``)."""
     if mesh is None:
-        return (lambda batch: batch), (lambda grads: grads)
+        return ((lambda batch: batch), (lambda grads: grads),
+                contextlib.nullcontext)
     from repro.distributed import sharding as sharding_lib
     if rules is None:
         rules = sharding_lib.RL_AGENT_RULES
     return (lambda batch: sharding_lib.shard_rollout(batch, mesh, rules),
-            lambda grads: sharding_lib.replicate(grads, mesh))
+            lambda grads: sharding_lib.replicate(grads, mesh),
+            lambda: sharding_lib.use_rules(mesh, rules))
 
 
 def _make_lm_mesh_fns(mesh, rules):
@@ -87,10 +91,10 @@ def make_train_step(agent_apply: Callable, opt, train_cfg, *,
     falls out of sharding propagation (module docstring).
     vtrace_impl: 'scan' or 'kernel' (the Pallas V-trace recursion).
     """
-    shard_batch, shard_grads = _make_shard_fns(mesh, rules)
+    shard_batch, shard_grads, model_ctx = _make_shard_fns(mesh, rules)
 
     def loss_fn(params, batch):
-        with jax.named_scope("learner_forward"):
+        with jax.named_scope("learner_forward"), model_ctx():
             out = agent_apply(params, batch["obs"])   # (T+1, B, ...)
         with jax.named_scope("loss"):
             target_logits = out.policy_logits[:-1]
@@ -148,7 +152,7 @@ def make_recurrent_train_step(agent_apply, opt, train_cfg, *,
     unroll from the stored initial core_state (TorchBeast's learner does
     exactly this), then V-trace as usual. batch adds "core_state".
     mesh/rules/vtrace_impl as in ``make_train_step``."""
-    shard_batch, shard_grads = _make_shard_fns(mesh, rules)
+    shard_batch, shard_grads, model_ctx = _make_shard_fns(mesh, rules)
 
     def loss_fn(params, batch):
         def step(core_state, xs):
@@ -159,7 +163,7 @@ def make_recurrent_train_step(agent_apply, opt, train_cfg, *,
         # re-run the recurrence over the T+1 observations from the stored
         # initial core_state; pre_done[t] zeroes the state exactly where
         # the actor did (fresh-episode observations)
-        with jax.named_scope("learner_forward"):
+        with jax.named_scope("learner_forward"), model_ctx():
             _, (logits, baselines) = jax.lax.scan(
                 step, batch["core_state"], (batch["obs"], batch["pre_done"]))
         with jax.named_scope("loss"):

@@ -22,6 +22,7 @@ NEG_INF = -2.0e38
 
 from repro.kernels import decode_attention as _dec  # noqa: E402
 from repro.kernels import flash_attention as _fa  # noqa: E402
+from repro.kernels import maxpool as _mp  # noqa: E402
 from repro.kernels import ref as _ref  # noqa: E402
 from repro.kernels import ssd_chunk as _ssd  # noqa: E402
 from repro.kernels import vtrace as _vt  # noqa: E402
@@ -49,19 +50,31 @@ def vtrace_acc(deltas, dcs, *, block_b=128, interpret=None):
                            interpret=resolve_interpret(interpret))
 
 
-def _per_device_columns(fn, mesh, b):
-    """``fn`` over (T, B) arrays, run by each device of ``mesh`` on its
-    own batch columns: XLA does not partition a Mosaic kernel, so a
-    sharded caller must ``shard_map`` it. Replicated when B does not
-    divide over the data axes."""
+def maxpool_fwd(x, *, interpret=None):
+    return _mp.maxpool_fwd(x, interpret=resolve_interpret(interpret))
+
+
+def maxpool_bwd(idx, dy, *, hw, interpret=None):
+    return _mp.maxpool_bwd(idx, dy, hw=hw,
+                           interpret=resolve_interpret(interpret))
+
+
+def per_device(fn, mesh, size, dim):
+    """``fn``, run by each device of ``mesh`` on its own slice of
+    dimension ``dim`` (of ``size``) of every argument and result: XLA
+    does not partition a Mosaic kernel, so a sharded caller must
+    ``shard_map`` it. Replicated when ``size`` does not divide over the
+    data axes; ``fn`` itself without a mesh."""
+    if mesh is None:
+        return fn
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.sharding import data_axes
     axes = data_axes(mesh)
-    size = math.prod(mesh.shape[a] for a in axes)
-    spec = P(None, axes) if axes and b % size == 0 else P()
-    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec),
-                         out_specs=spec, check_vma=False)
+    n = math.prod(mesh.shape[a] for a in axes)
+    spec = P(*[None] * dim, axes) if axes and size % n == 0 else P()
+    return jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
+                         check_vma=False)
 
 
 def vtrace_from_importance_weights_kernel(
@@ -85,9 +98,9 @@ def vtrace_from_importance_weights_kernel(
     values_tp1 = jnp.concatenate([values[1:], bootstrap_value[None]], 0)
     deltas = clipped_rhos * (rewards + discounts * values_tp1 - values)
 
-    acc_fn = lambda d, dc: vtrace_acc(d, dc, interpret=interpret)  # noqa: E731
-    if mesh is not None:
-        acc_fn = _per_device_columns(acc_fn, mesh, deltas.shape[1])
+    acc_fn = per_device(
+        lambda d, dc: vtrace_acc(d, dc, interpret=interpret), mesh,
+        deltas.shape[1], dim=1)      # split by batch column
     acc = acc_fn(deltas, discounts * cs)
     vs = values + acc
     vs_tp1 = jnp.concatenate([vs[1:], bootstrap_value[None]], 0)

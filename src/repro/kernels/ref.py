@@ -92,3 +92,31 @@ def ref_ssd_chunk(c, b, xdt, da, h_prev):
     h_new = h * jnp.exp(acs[:, -1])[:, None, None] + \
         jnp.einsum("glp,gln,gl->gpn", x, b, w)
     return y.astype(xdt.dtype), h_new
+
+
+def _pool_taps(h, w):
+    """(window position, input row slice, input column slice) of the 3x3,
+    stride-2 pool, on an input padded by one on each side."""
+    ho, wo = (h + 1) // 2, (w + 1) // 2
+    return [(3 * dr + dc, slice(dr, dr + 2 * ho - 1, 2),
+             slice(dc, dc + 2 * wo - 1, 2))
+            for dr in range(3) for dc in range(3)]
+
+
+def ref_maxpool_fwd(x):
+    """Oracle for kernels/maxpool.py on its (H, W, C, N) view: the window's
+    max and the position (row-major, 0..8) of its first maximum."""
+    h, w = x.shape[:2]
+    xp = jnp.pad(x, ((1, 1), (1, 1), (0, 0), (0, 0)),
+                 constant_values=-jnp.inf)
+    taps = jnp.stack([xp[rows, cols] for _, rows, cols in _pool_taps(h, w)])
+    return taps.max(0), jnp.argmax(taps, 0).astype(jnp.int8)
+
+
+def ref_maxpool_bwd(idx, dy, hw):
+    """dx (H, W, C, N): each output's dy added at its window's winner."""
+    h, w = hw
+    dxp = jnp.zeros((h + 2, w + 2) + dy.shape[2:], dy.dtype)
+    for k, rows, cols in _pool_taps(h, w):
+        dxp = dxp.at[rows, cols].add(jnp.where(idx == k, dy, 0))
+    return dxp[1:h + 1, 1:w + 1]

@@ -282,6 +282,9 @@ def kernel_roofline(kernel: str, *, dtype_bytes: int = 2,
       decode_attention  b, h, kh, s, hd
       ssd_chunk         bh, l, n, p
       vtrace            t, b
+      maxpool_fwd/_bwd  h, w, c, n (the pool's input; FLOPs here are the
+                        window's 8 comparisons, or its 9 selects, per
+                        output)
     """
     from repro.launch import mesh as mesh_lib
     if kernel == "flash_attention":
@@ -312,6 +315,12 @@ def kernel_roofline(kernel: str, *, dtype_bytes: int = 2,
         t, b = dims["t"], dims["b"]
         flops = 3.0 * t * b                             # one fma + mul per cell
         bytes_ = 4 * 3 * t * b                          # deltas, dcs, out fp32
+    elif kernel in ("maxpool_fwd", "maxpool_bwd"):
+        h, w, c, n = dims["h"], dims["w"], dims["c"], dims["n"]
+        outs = ((h + 1) // 2) * ((w + 1) // 2) * c * n
+        flops = (8.0 if kernel == "maxpool_fwd" else 9.0) * outs
+        # x (or dx) once, the pooled max (or dy) and the int8 index once
+        bytes_ = dtype_bytes * (h * w * c * n + outs) + outs
     else:
         raise ValueError(f"unknown kernel {kernel}")
     compute_s = flops / mesh_lib.PEAK_FLOPS_BF16

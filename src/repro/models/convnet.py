@@ -2,7 +2,8 @@
 
 ``impala_deep``: the IMPALA "deep" ResNet (15 conv layers: 3 sections of
 conv + maxpool + 2 residual blocks; FC 256; policy + baseline heads) — the
-network TorchBeast trains on Atari (§4, without LSTM).
+network TorchBeast trains on Atari (§4, without LSTM). Its pools'
+gradient runs on the Pallas kernels of ``kernels/maxpool.py``.
 
 ``minatar_net``: the small ConvNet of the paper's MinAtar adaptation example
 (Fig. 2): conv3x3x16 + FC 128 + heads.
@@ -14,12 +15,15 @@ restored so (T, B, ...) learner batches work directly.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.distributed import sharding as sharding_lib
+from repro.kernels import ops as kernel_ops
 from repro.models.common import param, split_params
 
 
@@ -62,9 +66,45 @@ def _linear(p, x):
 
 
 def _maxpool(x):
+    """3x3 max-pool at stride 2 over NHWC. Differentiated, it runs the
+    Pallas kernels of ``kernels/maxpool.py`` and keeps each window's
+    winner index, not x, for the backward; under a learner's mesh
+    (``sharding.use_rules``) each device pools its own batch rows."""
+    state = sharding_lib.current_rules()
+    with jax.named_scope("maxpool"):
+        return _pool(x, x.shape[1:3], state[0] if state else None)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _pool(x, hw, mesh):
+    del hw, mesh
     return jax.lax.reduce_window(
         x, -jnp.inf, jax.lax.max, (1, 3, 3, 1), (1, 2, 2, 1),
         [(0, 0), (1, 1), (1, 1), (0, 0)])
+
+
+def _pool_fwd(x, hw, mesh):
+    def run(x):     # the kernels' (H, W, C, N) view is XLA's own layout
+        out, idx = kernel_ops.maxpool_fwd(x.transpose(1, 2, 3, 0))
+        # Without the barrier XLA fuses the next convolution into the
+        # transpose back and saves its output beside x + y for the
+        # backward: 0.7 ms a learner step by the v5e compiler's cost model.
+        out = jax.lax.optimization_barrier(out.transpose(3, 0, 1, 2))
+        return out, idx.transpose(3, 0, 1, 2)
+
+    return kernel_ops.per_device(run, mesh, x.shape[0], dim=0)(x)
+
+
+def _pool_bwd(hw, mesh, idx, dy):
+    def run(idx, dy):
+        dx = kernel_ops.maxpool_bwd(idx.transpose(1, 2, 3, 0),
+                                    dy.transpose(1, 2, 3, 0), hw=hw)
+        return dx.transpose(3, 0, 1, 2)
+
+    return (kernel_ops.per_device(run, mesh, dy.shape[0], dim=0)(idx, dy),)
+
+
+_pool.defvjp(_pool_fwd, _pool_bwd)
 
 
 # ---------------------------------------------------------------------------

@@ -127,18 +127,19 @@ def test_kernel_audit_real_kernels_named():
     _, tables = audit_kernels(["zamba2-2.7b"])
     assert {t["kernel"]: t["name"] for t in tables} == {
         k: k for k in ("flash_attention", "decode_attention", "ssd_chunk",
-                       "vtrace")}
+                       "vtrace", "maxpool_fwd", "maxpool_bwd")}
 
 
 def test_kernel_audit_real_kernels_clean_and_complete():
-    """The shipped kernels pass, and the footprint table covers all four
-    kernels for every audited arch."""
+    """The shipped kernels pass, and the footprint table covers every
+    kernel for every audited arch."""
     findings, tables = audit_kernels(["qwen3-4b", "zamba2-2.7b"])
     assert findings == []
     for arch in ("qwen3-4b", "zamba2-2.7b"):
         kernels = {t["kernel"] for t in tables if t["arch"] == arch}
         assert kernels == {"flash_attention", "decode_attention",
-                           "ssd_chunk", "vtrace"}
+                           "ssd_chunk", "vtrace", "maxpool_fwd",
+                           "maxpool_bwd"}
     for t in tables:
         assert t["vmem_total_bytes"] <= t["vmem_budget_bytes"]
         assert t["roofline"]["flops"] > 0

@@ -19,10 +19,13 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import decode_attention as dec
 from repro.kernels import flash_attention as fa
+from repro.kernels import maxpool as mp
+from repro.kernels import ops as kernel_ops
 from repro.kernels import ssd_chunk as ssd
 from repro.kernels import vtrace as vt
+from repro.models import convnet
 
-BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
 
 
 @pytest.fixture(scope="module")
@@ -105,6 +108,63 @@ def test_ssd_chunk_compiles(one_chip, length):
              ((bh, p, n), F32))
 
 
+@pytest.mark.parametrize("h,c", [(84, 16), (21, 32)],
+                         ids=["section1-84", "section3-21"])
+def test_maxpool_compiles(one_chip, h, c):
+    """The deep ResNet's first and last pools over the learner's 2,592
+    frames, on the (H, W, C, N) view: the forward and the backward."""
+    n, ho = 81 * 32, mp.pooled_size(h)
+    _compile(functools.partial(mp.maxpool_fwd, interpret=False), one_chip,
+             ((h, h, c, n), F32))
+    _compile(functools.partial(mp.maxpool_bwd, hw=(h, h), interpret=False),
+             one_chip, ((ho, ho, c, n), I8), ((ho, ho, c, n), F32))
+
+
+def _impala_hlo(one_chip, monkeypatch, grad, n):
+    """The deep ResNet at Atari shapes over n frames, compiled for the
+    chip: its parameter gradient, or the actors' forward."""
+    monkeypatch.setattr(kernel_ops, "resolve_interpret",
+                        lambda interpret=None: False)  # the chip's choice
+    init_fn, apply_fn = convnet.impala_deep((84, 84, 4), 18)
+    params = jax.eval_shape(lambda: convnet.init_agent(
+        init_fn, jax.random.PRNGKey(0))[0])
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), params)
+    obs = jax.ShapeDtypeStruct((n, 84, 84, 4), F32, sharding=one_chip)
+
+    def loss(p, o):
+        out = apply_fn(p, o)
+        return out.policy_logits.sum() + out.baseline.sum()
+
+    fn = jax.grad(loss) if grad else apply_fn
+    with jax.default_matmul_precision("default"):
+        return jax.jit(fn).lower(params, obs).compile().as_text()
+
+
+def test_impala_grad_pools_without_select_and_scatter(one_chip,
+                                                      monkeypatch):
+    """The learner's gradient at T=80, B=32 runs the pool kernels and no
+    select-and-scatter, and neither copies nor transposes a section-1
+    pool input: the kernels' view is XLA's own layout."""
+    text = _impala_hlo(one_chip, monkeypatch, grad=True, n=81 * 32)
+    assert "select-and-scatter" not in text
+    calls = re.findall(r"%(maxpool_(?:fwd|bwd))\.\d+ = .*custom-call\(",
+                       text)
+    assert sorted(calls) == ["maxpool_bwd"] * 3 + ["maxpool_fwd"] * 3
+    pool_sized = re.findall(r"= f32\[2592,84,84,16\]\{[^}]*\} "
+                            r"(?:copy|transpose)\(", text)
+    assert not pool_sized, pool_sized
+
+
+def test_impala_actor_forward_pools_with_reduce_window(one_chip,
+                                                       monkeypatch):
+    """The actors' forward (batch 32, not differentiated) keeps the
+    reduce-window pool and runs no kernel."""
+    text = _impala_hlo(one_chip, monkeypatch, grad=False, n=32)
+    assert text.count("reduce-window(") == 3
+    assert "tpu_custom_call" not in text
+
+
 def test_kernels_named_in_compiled_program(one_chip):
     """The compiled program names each kernel's custom call after its
     pallas_call name=, which is what a device trace shows."""
@@ -125,6 +185,11 @@ def test_kernels_named_in_compiled_program(one_chip):
                       ((2, 64, 64), F32), ((2, 64, 64), F32),
                       ((2, 64, 64), F32), ((2, 64, 1), F32),
                       ((2, 64, 64), F32)),
+        "maxpool_fwd": (functools.partial(mp.maxpool_fwd, interpret=False),
+                        ((8, 8, 8, 128), F32)),
+        "maxpool_bwd": (functools.partial(mp.maxpool_bwd, hw=(8, 8),
+                                          interpret=False),
+                        ((4, 4, 8, 128), I8), ((4, 4, 8, 128), F32)),
     }
     for name, (fn, *shapes) in cases.items():
         text = _compile(fn, one_chip, *shapes)
