@@ -32,6 +32,11 @@ def parse_args(argv=None):
                     help="instead of a run: read the compared numbers of "
                          "the program, the control and the faults on this "
                          "many seeds from --seed on")
+    ap.add_argument("--sweep", default="",
+                    help="instead of a run, for a serving mix: offer each "
+                         "of these comma-separated rates (requests/s) for "
+                         "--seconds and report whether the server keeps "
+                         "pace")
     return ap.parse_args(argv)
 
 
@@ -177,6 +182,18 @@ class CompileClock:
                 f"{self.cache_hits} persistent-cache hits")
 
 
+def abstract(tree):
+    """The shapes, types and placements of a call's arguments, as
+    ``jax.jit(f).lower`` needs them to give the program that the call ran
+    (an array that the program did not place on a device is uncommitted,
+    and so is its stand-in)."""
+    import jax
+    import numpy as np
+    return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        np.shape(x), x.dtype, sharding=x.sharding
+        if getattr(x, "_committed", False) else None), tree)
+
+
 def memory_peak_bytes(devices) -> int:
     return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
                for d in devices)
@@ -198,7 +215,8 @@ def result_line(*, correct, attempted, failed, metrics, device, checks,
            "failed": int(failed), "metrics": metrics, "device": device}
     if breakdown is not None:
         out["breakdown"] = breakdown
-    out["checks"] = {k: {"value": v, "limit": lim}
+    # a number that could not be read is inf, and JSON has no inf: null
+    out["checks"] = {k: {"value": v if finite(v) else None, "limit": lim}
                      for k, (v, lim) in checks.items()}
     for k, (v, lim) in checks.items():
         log(f"check {k}: {v!r} limit {lim!r}")

@@ -9,8 +9,16 @@ the system under test, warms up every shape the cell uses, measures for
 ``--seconds`` and checks what the timed path produced against a plain
 reference. ``--trace 0`` reports the cell's end-to-end metrics;
 ``--trace 1`` traces the window and reports its per-layer metrics, each
-read by metrics/<name>.py. The last stdout line is one JSON object.
-Without enough TPU chips it exits nonzero and prints no result.
+read by metrics/<name>.py, which find in their context the device
+trace's reduction and, where the runner gives its programs' compiled
+text (``Run.hlo_texts()``), the program's spans (spans.py). The last
+stdout line is one JSON object. Without enough TPU chips it exits
+nonzero and prints no result.
+
+``--calibrate N`` reads the compared numbers of the program, the control
+and the planted faults on N seeds instead of a run; ``--sweep R1,R2,..``
+offers a serving mix at each rate for ``--seconds`` and reports whether
+the server keeps pace.
 """
 
 import time
@@ -19,6 +27,7 @@ T_START = time.monotonic()
 
 import gc  # noqa: E402
 import importlib  # noqa: E402
+import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -28,7 +37,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 sys.path[:0] = [os.path.join(ROOT, "src"), os.path.dirname(HERE)]
 
-from chip import devtrace, flops, harness  # noqa: E402
+from chip import devtrace, flops, harness, spans  # noqa: E402
 from chip.harness import log  # noqa: E402
 
 
@@ -50,14 +59,20 @@ class Tracer:
         self.window_s = time.perf_counter() - self.t0
         jax.profiler.stop_trace()
 
-    def reduce(self):
-        events = devtrace.events_from_xplane(self.dir)
+    def reduce(self, hlo_texts=None):
+        """(devtrace's reduction, ``spans.reduce_spans`` of the trace or
+        None without ``hlo_texts``, the number of device events)."""
+        trace = spans.read_xplane(self.dir)
         if self.keep:
-            devtrace.save_events(events, os.path.join(self.dir,
-                                                      "events.json"))
+            devtrace.save_events(trace["device"],
+                                 os.path.join(self.dir, "events.json"))
         else:
             shutil.rmtree(self.dir, ignore_errors=True)
-        return devtrace.reduce_events(events, self.window_s)
+        op_names = {p: spans.op_names_from_hlo(t)
+                    for p, t in (hlo_texts or {}).items()}
+        return (devtrace.reduce_events(trace["device"], self.window_s),
+                spans.reduce_spans(trace, op_names) if op_names else None,
+                len(trace["device"]))
 
 
 def execute(cell, args, devices) -> dict:
@@ -81,7 +96,16 @@ def execute(cell, args, devices) -> dict:
         log(f"warning: {clock.compiles - compiles_before} programs compiled "
             "inside the window")
     peak = harness.memory_peak_bytes(devices)
-    reduced = tracer.reduce() if tracer else None
+    if tracer:
+        t0 = time.monotonic()
+        texts = run.hlo_texts() if hasattr(run, "hlo_texts") else None
+        reduced, span_reduced, n_events = tracer.reduce(texts)
+        log(f"trace read in {time.monotonic() - t0:.1f}s "
+            f"({n_events} device events; "
+            f"{clock.summary()})")
+        if span_reduced is not None:
+            log(f"spans: {json.dumps(spans.layer_ms(span_reduced))}; "
+                f"clock {json.dumps(span_reduced['clock'])}")
     checks = run.check()
 
     device = {"platform": devices[0].platform,
@@ -98,10 +122,14 @@ def execute(cell, args, devices) -> dict:
         device.update(busy_s=reduced["busy_s"], window_s=reduced["window_s"])
         breakdown = {"device_ops": reduced["device_ops"],
                      "idle_gaps": reduced["idle_gaps"]}
+        if span_reduced is not None:
+            breakdown["idle_gaps_host"] = span_reduced["idle_gaps_host"]
+        # "spans": the program's scopes and host spans (spans.reduce_spans),
+        # where the runner names its programs' compiled text
         ctx = {"trace": reduced, "counters": run.counters,
                "peaks": flops.peaks(devices[0].device_kind),
                "chips": len(devices), "config": cell.config,
-               "traffic": cell.traffic}
+               "traffic": cell.traffic, "spans": span_reduced}
         metrics = harness.per_layer_metrics(cell, ctx)
     ok = run.failed == 0 and all(v <= lim for v, lim in checks.values())
     out = dict(correct=ok, attempted=run.attempted, failed=run.failed,
@@ -119,11 +147,15 @@ def main(argv=None):
     devices = harness.device_gate(cell.chips)
     from repro.kernels.compat import resolve_interpret
     log(f"jax ready {time.monotonic() - T_START:.1f}s")
-    if args.calibrate:
+    if args.calibrate or args.sweep:
         harness.use_compile_cache()
         runner = importlib.import_module(
             f"chip.runners.{cell.traffic['runner']}")
-        runner.Run(cell, args, devices).calibrate()
+        run = runner.Run(cell, args, devices)
+        if args.sweep:
+            run.sweep([float(r) for r in args.sweep.split(",")])
+        else:
+            run.calibrate()
         return
     result = execute(cell, args, devices)
     stats = resolve_interpret.stats()

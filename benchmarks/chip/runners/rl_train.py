@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chip import weights
+from chip import harness, weights
 from chip.envs import atari_synth
 from chip.harness import log
 from chip.reference import impala as ref
@@ -101,11 +101,13 @@ def readings(first, refrun, per_leaf=False):
 
 class _Recorder:
     """The source as the Runtime drives it: keeps a host copy of each batch
-    while ``keep`` is on (the set-up steps that the check replays)."""
+    while ``keep`` is on (the set-up steps that the check replays), and
+    the shapes, types and placements of a batch."""
 
     def __init__(self, source):
         self.source, self.kept, self.keep = source, [], True
         self.frames_per_batch = source.frames_per_batch
+        self.spec = None
 
     def start(self, params):
         self.source.start(params)
@@ -115,6 +117,8 @@ class _Recorder:
 
     def next_batch(self, params):
         batch = self.source.next_batch(params)
+        if self.spec is None:
+            self.spec = harness.abstract(batch)
         if self.keep:
             self.kept.append(_host(batch))
         return batch
@@ -176,6 +180,15 @@ class Run:
         source.keep = False
         first["batches"], source.kept = source.kept, []
         first["params_n"] = _host(rt.params)
+        # the learner step's and the unroll's arguments as the window passes
+        # them, for hlo_texts
+        src = source.source
+        self._programs = {
+            "jit_train_step": (step_fn, harness.abstract(
+                (rt.params, rt.opt_state, jnp.int32(0))) + (source.spec,)),
+            "jit_unroll": (src._unroll, harness.abstract(
+                (rt.params, src._carry,
+                 jax.random.split(src._key)[1])))}
         log(f"first {len(first['losses'])} steps from seed {seed}: "
             f"{time.perf_counter() - t0:.1f}s")
         return rt, first
@@ -241,6 +254,14 @@ class Run:
             f"the deadline); longest host gap between steps "
             f"{longest:.3f}s; last loss {losses[-1] if losses else None}")
         self.rt = None
+
+    def hlo_texts(self):
+        """{program: compiled HLO text} of the learner step and the actors'
+        unroll, which names the scope of each instruction (chip.spans);
+        lowered from the arguments' shapes, and compiled from the
+        persistent cache, after the window."""
+        return {name: fn.lower(*args).compile().as_text()
+                for name, (fn, args) in self._programs.items()}
 
     # -- correctness --------------------------------------------------------
 

@@ -102,12 +102,16 @@ def op_names_from_hlo(text: str) -> dict:
 
 def scope_of(op_name: str, scopes) -> tuple:
     """(innermost of ``scopes`` in ``op_name``, FORWARD or BACKWARD), or
-    ('', FORWARD) where none is. 'jit(f)/transpose(jvp(loss))/mul' ->
-    ('loss', BACKWARD)."""
-    for part in reversed(op_name.split("/")):
-        for token in reversed(_TOKEN.findall(part)):
+    ('', FORWARD) where none is; the pass is BACKWARD where the part that
+    holds the scope or any part enclosing it is a ``transpose(``.
+    'jit(f)/transpose(jvp(loss))/mul' -> ('loss', BACKWARD);
+    'jit(f)/transpose(jvp(net))/pool/mul' -> ('pool', BACKWARD)."""
+    parts = op_name.split("/")
+    for i in range(len(parts) - 1, -1, -1):
+        for token in reversed(_TOKEN.findall(parts[i])):
             if token in scopes:
-                return token, BACKWARD if "transpose(" in part else FORWARD
+                back = any("transpose(" in p for p in parts[:i + 1])
+                return token, BACKWARD if back else FORWARD
     return "", FORWARD
 
 

@@ -41,15 +41,21 @@ def test_contract_shape():
     e2e = {m["name"] for m in b["end_to_end"]}
     for m in b["per_layer"]:
         assert m["moves"] in e2e
+    # a metric's cells, where listed, are named cells, and at least one
+    cells = {w["name"] for w in b["workloads"]}
+    for m in b["end_to_end"] + b["per_layer"]:
+        if "workloads" in m:
+            assert m["workloads"] and set(m["workloads"]) <= cells, m
     assert len(json.dumps(b)) < 64 * 1024
 
 
 @pytest.mark.parametrize("cell", [w["name"] for w in _bench()["workloads"]])
 def test_every_cell_resolves_by_name(cell):
     c = harness.resolve_cell(cell)
-    assert c.traffic["runner"] == "rl_train"
-    assert os.path.exists(os.path.join(ROOT, "benchmarks", "chip", "runners",
-                                       c.traffic["runner"] + ".py"))
+    runner = os.path.join(ROOT, "benchmarks", "chip", "runners",
+                          c.traffic["runner"] + ".py")
+    assert os.path.exists(runner)
+    assert "\nclass Run:" in open(runner).read()
     assert c.config["name"] == c.config_name
     reported = {m["name"] for m in c.end_to_end}
     assert "setup_s" in reported and len(reported) >= 2
@@ -109,6 +115,83 @@ def test_new_cell_is_found_from_data_files_alone(tmp_path):
         harness.resolve_cell("no-such-cell", root=str(tmp_path))
 
 
+# The serving metrics as the first serving cell's PR adds them to
+# BENCHMARK.json (a metric that no cell reports may not stand there); the
+# runner and the three readers are in place. The bounds here are the
+# stand-in's; that cell sets its own from its runs.
+SERVING_E2E = [
+    {"name": "tbt_p50_ms", "unit": "ms", "better": "lower", "bound": 0.036,
+     "source": "host_clock"},
+    {"name": "tbt_p90_ms", "unit": "ms", "better": "lower", "bound": 0.034,
+     "source": "host_clock"}]
+SERVING_PER_LAYER = [
+    {"name": "decode_ms.chat", "unit": "ms", "better": "lower",
+     "source": "device_trace", "layer": "decode session",
+     "moves": "tbt_p50_ms"},
+    {"name": "admit_ms.chat", "unit": "ms", "better": "lower",
+     "source": "device_trace", "layer": "decode session",
+     "moves": "tbt_p90_ms"},
+    {"name": "idle_share.chat", "unit": "%", "better": "lower",
+     "source": "device_trace", "layer": "device", "moves": "tbt_p50_ms"}]
+
+
+def test_serving_cell_is_found_from_data_files_alone(tmp_path):
+    """A serving cell added as a configuration, a chat mix and entries in
+    BENCHMARK.json only: it names the serve_chat runner, reports the
+    serving end-to-end metrics and the three chat readers, and every
+    existing file is left as it was."""
+    bench_dir = tmp_path / "benchmarks" / "chip"
+    shutil.copytree(os.path.join(ROOT, "benchmarks", "chip"), bench_dir,
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    before = {p: p.read_bytes() for p in bench_dir.rglob("*") if p.is_file()}
+    b = _bench()
+    cfg = {"name": "zamba2-chat-model", "source": "https://example.org/cfg",
+           "model": {"arch": "zamba2-2.7b"}, "serve": {"max_batch": 16,
+                                                      "max_len": 1024},
+           "reference": "zamba2_chat_model", "limits": {}, "reduced": []}
+    (bench_dir / "configs" / "zamba2-chat-model.json").write_text(
+        json.dumps(cfg))
+    mix = {"runner": "serve_chat", "rate_per_s": 8.0, "arrival_cv": 2.0,
+           "prompt_len": {"median": 256, "sigma": 0.8, "min": 32,
+                          "max": 896},
+           "output_len": {"median": 64, "sigma": 0.6, "min": 8, "max": 256},
+           "check_requests": 16, "trace_seconds": 5}
+    (bench_dir / "traffic" / "chat.json").write_text(json.dumps(mix))
+    b["configs"].append({"name": "zamba2-chat-model",
+                         "source": cfg["source"],
+                         "file": "benchmarks/chip/configs/"
+                                 "zamba2-chat-model.json",
+                         "reduced": [], "why": "a serving model"})
+    b["workloads"].append({"name": "zamba2-chat", "config":
+                           "zamba2-chat-model", "traffic": "chat",
+                           "chips": 1, "why": "open-loop chat"})
+    chat = [m["name"] for m in SERVING_E2E + SERVING_PER_LAYER]
+    assert not set(chat) & {m["name"] for m in b["end_to_end"]
+                            + b["per_layer"]}
+    b["end_to_end"] += [dict(m, workloads=["zamba2-chat"])
+                        for m in SERVING_E2E]
+    b["per_layer"] += [dict(m, workloads=["zamba2-chat"])
+                       for m in SERVING_PER_LAYER]
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(b))
+    c = harness.resolve_cell("zamba2-chat", root=str(tmp_path))
+    assert c.traffic["runner"] == "serve_chat"
+    assert os.path.exists(bench_dir / "runners" / "serve_chat.py")
+    assert c.config["reference"] == "zamba2_chat_model"
+    reported = {m["name"] for m in c.end_to_end}
+    assert reported == {"setup_s", "tbt_p50_ms", "tbt_p90_ms"}
+    assert {m["name"] for m in c.per_layer} == {"decode_ms.chat",
+                                               "admit_ms.chat",
+                                               "idle_share.chat"}
+    for m in c.per_layer:
+        assert m["moves"] in reported
+        assert callable(harness.metric_reader(m["name"], str(tmp_path)))
+    # impala-atari reports none of them
+    i = harness.resolve_cell("impala-atari", root=str(tmp_path))
+    assert not {m["name"] for m in i.end_to_end + i.per_layer} & set(chat)
+    for p, data in before.items():
+        assert p.read_bytes() == data, p
+
+
 def _run_py(args, cwd):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("PYTHONPATH", None)
@@ -138,7 +221,12 @@ def test_run_exits_nonzero_in_a_bare_checkout(tmp_path):
     assert out.stdout.strip() == ""
 
 
-@pytest.mark.parametrize("metric", [m["name"] for m in _bench()["per_layer"]])
+# every reader file, listed in BENCHMARK.json or waiting for its first cell
+READERS = sorted(f[:-len(".py")] for f in os.listdir(
+    os.path.join(ROOT, "benchmarks", "chip", "metrics")) if f.endswith(".py"))
+
+
+@pytest.mark.parametrize("metric", READERS)
 def test_reader_of_an_empty_trace_reads_nothing(metric):
     """A reader that finds nothing returns None (never a 0 share)."""
     from chip import devtrace

@@ -89,6 +89,24 @@ def test_scope_of_innermost_and_backward():
                           "_slice", unroll) == ("rollout", spans.FORWARD)
 
 
+def test_scope_of_takes_the_pass_from_an_enclosing_part():
+    """A scope nested in the backward of another (the pools inside the
+    learner's forward): the pass comes from the enclosing transpose."""
+    scopes = ("learner_forward", "maxpool")
+    assert spans.scope_of(
+        f"{TS}/transpose(jvp(learner_forward))/maxpool/jit(maxpool_bwd)/"
+        "pallas_call", scopes) == ("maxpool", spans.BACKWARD)
+    assert spans.scope_of(
+        f"{TS}/jvp(learner_forward)/maxpool/jit(maxpool_fwd)/pallas_call",
+        scopes) == ("maxpool", spans.FORWARD)
+    assert spans.scope_of(f"{TS}/transpose(jvp(learner_forward))/maxpool",
+                          ("learner_forward",)) == ("learner_forward",
+                                                    spans.BACKWARD)
+    # a transpose nested inside the scope does not make it backward
+    assert spans.scope_of(f"{TS}/jvp(learner_forward)/maxpool/transpose",
+                          scopes) == ("maxpool", spans.FORWARD)
+
+
 def test_scopes_forward_backward_per_call():
     trace = {"device": _learner_step(0) + _learner_step(20 * MS),
              "host": _host_step(-5 * MS) + _host_step(15 * MS)}
@@ -200,6 +218,28 @@ def test_read_xplane_keeps_the_runtime_spans(tmp_path, capsys):
     assert out["clock"]["ok"] is False and out["host_step_ms.train"] is None
 
 
+def test_tracer_hands_spans_on(tmp_path):
+    """run.Tracer's ``reduce``: the device reduction, and the spans of the
+    programs named, the host spans with them; no programs, no spans."""
+    import importlib.util
+    import jax
+    from jax.profiler import StepTraceAnnotation
+    spec = importlib.util.spec_from_file_location(
+        "chipbench_run", os.path.join(HERE, "..", "run.py"))
+    run = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(run)
+    tracer = run.Tracer(str(tmp_path))
+    tracer.start()
+    with StepTraceAnnotation("train", step_num=0):
+        jax.block_until_ready(jax.numpy.ones(4) + 1)
+    tracer.stop()
+    reduced, span_reduced, n_events = tracer.reduce({spans.LEARNER: ""})
+    assert reduced["window_s"] == tracer.window_s
+    assert n_events >= 0
+    assert span_reduced["host_spans"]["train"][0] == 1
+    assert tracer.reduce()[1] is None     # no programs named, no spans
+
+
 def test_recorded_v5e_step_with_spans():
     """One impala-atari step from a v5e trace: the scopes account for
     over 96% of each program, in the same milliseconds as devtrace's
@@ -233,3 +273,37 @@ def test_recorded_v5e_step_with_spans():
     assert r["clock"]["ok"] and r["clock"]["matched"] == 1
     assert r["clock"]["lead_ms"] == pytest.approx(1752.2, abs=0.1)
     assert r["host_spans"]["train"][0] == 1
+
+
+def test_training_runner_names_its_programs_text():
+    """rl_train's hlo_texts, lowered after the window from the shapes that
+    set-up kept, give the learner step's and the unroll's compiled text
+    with the scopes that spans.SCOPES reads."""
+    import copy
+    import types
+    import jax
+    from chip import harness
+    from chip.runners import rl_train
+    from repro.launch import compile_cache
+    cell = harness.resolve_cell("impala-atari")
+    cfg = copy.deepcopy(cell.config)
+    cfg["model"].update(obs_shape=[12, 12, 4], channels=[4, 8, 8], fc=16)
+    cfg["env"].update(obs_shape=[12, 12, 4], sprite_half=1)
+    cell.config = cfg
+    cell.traffic = dict(cell.traffic, unroll_length=5, batch_per_chip=4)
+    args = types.SimpleNamespace(seed=2**32 + 5, seconds=0.5, trace=0,
+                                 calibrate=0)
+    real = compile_cache.use_compile_cache
+    compile_cache.use_compile_cache = lambda: None   # no cache on the CPU
+    try:
+        run = rl_train.Run(cell, args, jax.devices()[:1])
+        run.setup()
+        run.window(0.5, None)
+        texts = run.hlo_texts()
+    finally:
+        compile_cache.use_compile_cache = real
+    assert set(texts) == {spans.LEARNER, "jit_unroll"}
+    for prog, text in texts.items():
+        names = set(spans.op_names_from_hlo(text).values())
+        for scope in spans.SCOPES[prog]:
+            assert any(scope in n for n in names), (prog, scope)
